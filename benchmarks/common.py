@@ -21,6 +21,27 @@ SERIES = {
 }
 
 OUT_DIR = Path(os.environ.get("BENCH_OUT", "artifacts/bench"))
+REPO_ROOT = Path(__file__).resolve().parents[1]
+
+
+def enable_compile_cache() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache for every compile of
+    this process and return its directory (None without jax).
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
+    stands; otherwise the cache lives at the fixed ``<repo>/.jax_cache``
+    (the path is part of the cache key, so it never depends on a
+    temporary name, PID or time)."""
+    try:
+        import jax
+    except ImportError:
+        return None
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # the protocol kernels compile in well under JAX's default 1 s floor
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return cache_dir
 
 
 def make_rt(series: str, workers: int, **kw) -> RegCScaleRuntime:

@@ -52,13 +52,19 @@ N_TENANTS = 16
 SEED = 7
 
 
+def serve(rt, driver: str):
+    """Run the fig8 request stream for ``rt.W`` decode slots on ``rt``."""
+    p = rt.W
+    return kv_serving(rt, REQ_PER_SLOT * p, tok_words=TOK_WORDS,
+                      max_tokens=MAX_TOKENS, attn_window=ATTN_WINDOW,
+                      n_tenants=N_TENANTS, burst_mean=max(2, p // 8),
+                      gap_max=2, seed=SEED, driver=driver)
+
+
 def serve_point(series: str, p: int, driver: str, *, backend="numpy"):
     rt = make_rt(series, p, cache_pages=CACHE_PAGES, backend=backend)
     t0 = time.perf_counter()
-    rep = kv_serving(rt, REQ_PER_SLOT * p, tok_words=TOK_WORDS,
-                     max_tokens=MAX_TOKENS, attn_window=ATTN_WINDOW,
-                     n_tenants=N_TENANTS, burst_mean=max(2, p // 8),
-                     gap_max=2, seed=SEED, driver=driver)
+    rep = serve(rt, driver)
     return rt, rep, time.perf_counter() - t0
 
 

@@ -80,19 +80,10 @@ print("JSON" + json.dumps(rows))
 
 
 def main(argv=None):
-    # the trainer needs shard_map + host-device meshes; repro.compat shims
-    # both back to jax 0.4.x, but anything older predates the experimental
-    # shard_map API entirely — record a clear skip instead of a deep error
-    import jax
-    import re
-    ver = tuple(int(m.group()) for m in
-                (re.match(r"\d+", x) for x in jax.__version__.split(".")[:3])
-                if m)
-    if len(ver) == 3 and ver < (0, 4, 30):
-        print(f"regc_training: jax {jax.__version__} < 0.4.30 lacks a "
-              "usable shard_map; skipping", flush=True)
-        return []
     env = dict(os.environ)
+    # the 8-way DP mesh is 8 virtual CPU devices by design: the child
+    # never reaches for an accelerator the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -34,12 +35,15 @@ def ensure_artifacts(variant: str = "baseline") -> bool:
     print(f"no dry-run artifacts under {ART}; generating seed cells "
           f"{SEED_CELLS} (use `python -m repro.launch.dryrun --all` for "
           "the full sweep)", flush=True)
+    # the dry run lowers for placeholder CPU devices by design: the child
+    # never reaches for an accelerator this process may hold
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     for arch, shape in SEED_CELLS:
         try:
             r = subprocess.run(
                 [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
                  "--shape", shape, "--mesh", "single", "--out", str(ART)],
-                capture_output=True, text=True, timeout=560)
+                capture_output=True, text=True, timeout=560, env=env)
         except (subprocess.TimeoutExpired, OSError) as e:
             print(f"dry-run seed cell {arch}/{shape} failed: {e}", flush=True)
             continue

@@ -55,6 +55,7 @@ def main(argv=None):
                             kv_serving, lock_contention,
                             molecular_dynamics, races, recovery,
                             regc_training, roofline, stream_triad)
+    common.enable_compile_cache()
 
     sections = []
     for d in drivers:

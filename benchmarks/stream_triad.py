@@ -21,6 +21,11 @@ CORES = (1, 2, 4, 8, 16, 32, 64, 128, 256)
 # Fig-4 cache size: fits the small problem, spills at 2x (also imported
 # by the tests that re-derive committed CSV points)
 SPILL_CACHE_PAGES = 3 * (N_BASE // 1024) + 64
+# mid-op refetch torture: 128-page blocks per worker, 8-page sliding
+# windows, a cache of ~1.2 of the 16-page read+write window pair
+REFETCH_WORDS_PER_WORKER = 1 << 17
+REFETCH_WIDTH_PAGES = 8
+REFETCH_CACHE_PAGES = 20
 
 
 def spill_iters(iters: int) -> int:
@@ -115,13 +120,13 @@ def spill_heavy(iters: int, driver: str):
                      "t_wall_s": round(time.perf_counter() - t0, 4),
                      **traffic_fields(rt), **danger_fields(rt)})
     for p in (16, 64, 256):
-        n = (1 << 17) * p   # 8-page sliding windows over 128-page blocks
-        cache_pages = 20    # ~1.2 of the 16-page read+write window pair
+        n = REFETCH_WORDS_PER_WORKER * p
         ss = SteadyState()
         t0 = time.perf_counter()
-        rt = make_rt("samhita", p, cache_pages=cache_pages)
-        stream_refetch(rt, n, max(2, iters // 2), sweeps=2, width_pages=8,
-                       driver=driver, on_iter=ss)
+        rt = make_rt("samhita", p, cache_pages=REFETCH_CACHE_PAGES)
+        stream_refetch(rt, n, max(2, iters // 2), sweeps=2,
+                       width_pages=REFETCH_WIDTH_PAGES, driver=driver,
+                       on_iter=ss)
         rows.append({"figure": "fig4_refetch", "series": "samhita_refetch",
                      "p": p, "n": n, "driver": driver,
                      "t_iter_s": round(ss.per_iter(), 6),

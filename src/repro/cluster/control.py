@@ -31,6 +31,11 @@ drives a trace program round by round:
   to the unfailed single-process run — asserted by the cluster fuzz
   family and inside the fig10_availability bench.
 
+Shard processes are host replicas: each is spawned with
+``JAX_PLATFORMS=cpu``, so a shard built on a device backend runs its
+kernels on the CPU — 'pallas' in interpret mode, 'pallas-jit' on XLA's
+CPU backend — and never competes with the parent for the chip.
+
 Real RPC wall time (retries, deadlines) never touches the modeled
 clocks; it is accounted in the :class:`ClusterReport` through
 ``ChaosNet.backoff_seconds`` — the same capped backoff term the in-model
@@ -40,6 +45,7 @@ from __future__ import annotations
 
 import dataclasses
 import multiprocessing as mp
+import os
 import sys
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -153,7 +159,18 @@ class ClusterRuntime:
         proc = self._ctx.Process(target=shard_main,
                                  args=(child_conn, list(sys.path)),
                                  daemon=True)
-        proc.start()
+        # shards are host replicas: the spawned interpreter starts with
+        # JAX_PLATFORMS=cpu, so it never reaches for a chip this process
+        # may hold
+        prev = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            proc.start()
+        finally:
+            if prev is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = prev
         child_conn.close()     # keep only the shard's copy open there,
         #   so a dead shard turns into EOF on our end instead of a hang
         self._chans[rank] = ShardChannel(parent_conn, rank)

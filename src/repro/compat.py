@@ -1,46 +1,24 @@
-"""jax version-compatibility shims.
-
-The codebase targets the modern public APIs (``jax.shard_map``,
-``jax.sharding.AxisType``); this container ships jax 0.4.37 where
-``shard_map`` still lives in ``jax.experimental`` (with ``check_rep`` /
-``auto`` instead of ``check_vma`` / ``axis_names``) and meshes have no
-axis types.  Route every use through here so both generations work.
-"""
+"""jax API shims: one spelling of ``shard_map`` and of mesh construction
+for the whole codebase (the installed jax is 0.9)."""
 from __future__ import annotations
 
 import jax
 
-_new_shard_map = getattr(jax, "shard_map", None)
 
-if _new_shard_map is not None:
-    def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma: bool = False):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return _new_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_vma, **kw)
-else:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma: bool = False):
-        kw = {}
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - set(axis_names)
-            if auto:
-                kw["auto"] = auto
-        return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma, **kw)
+def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
+              check_vma: bool = False):
+    kw = {}
+    if axis_names is not None:
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the API supports them
-    (newer jax) and plain meshes otherwise — Auto is the old default."""
+    """``jax.make_mesh`` with Auto axis types on every axis."""
     kw = {}
     if devices is not None:
         kw["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kw["axis_types"] = (axis_type.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names), **kw)

@@ -519,7 +519,8 @@ class RegionDirectory:
             take = _ps.unpack_mask_rows(
                 _ps.take_first_k(_ps.pack_mask_rows(live[None]),
                                  np.asarray([k], np.int64),
-                                 backend=self.backend),
+                                 backend=self.backend,
+                                 stats=self.jit_stats),
                 live.size)[0]
             return take, int(np.flatnonzero(take)[-1]) + 1
         cs = np.cumsum(live, dtype=np.int64)

@@ -423,7 +423,7 @@ class RegCRuntime:
                     from repro.kernels.ops import diff_encode
                     import jax.numpy as jnp
                     mask, vals, count = diff_encode(
-                        jnp.asarray(curr), jnp.asarray(twin), interpret=True)
+                        jnp.asarray(curr), jnp.asarray(twin))
                     mask = np.asarray(mask[0], bool)
                     nwords = int(count[0])
                 except ImportError:

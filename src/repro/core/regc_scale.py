@@ -1309,13 +1309,16 @@ class RegCScaleRuntime:
         the ``rest`` bookkeeping, exactly as the unfused path) and packed
         shared-dirty candidate masks (dirty & >=2-coverage & active row).
         Returns ``(None, None)`` when page ids could overflow the int32
-        device arithmetic — the caller falls back to the unfused sweep."""
+        device arithmetic — the caller falls back to the unfused sweep,
+        and ``stats['jit_flush_fallbacks']`` counts it."""
         from repro.kernels import protocol_sweep as _ps
         R, W = len(cand), self.W
         nw_max = max(-(-int(d.cap) // 32) for d in cand)
         # page = base + col with col < nw_max*32; bound it in int32 (pads
         # are INT32_MAX and must stay strictly above every probed page)
         if max(int(d.page_hi) for d in cand) + nw_max * 32 >= (1 << 31) - 1:
+            self.stats["jit_flush_fallbacks"] = (
+                self.stats.get("jit_flush_fallbacks", 0) + 1)
             return None, None
         i32max = np.iinfo(np.int32).max
         bits = np.zeros((R, W, nw_max), np.uint32)

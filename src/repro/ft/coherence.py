@@ -204,11 +204,13 @@ def assert_bit_equal(a: RegCScaleRuntime, b: RegCScaleRuntime, ctx=""):
         av, bv = getattr(a.traffic, f.name), getattr(b.traffic, f.name)
         assert av == bv, (ctx, f.name, av, bv)
     np.testing.assert_array_equal(a.clock, b.clock, err_msg=str(ctx))
-    # jit_* counters record dispatch topology (how many fused device
+    # jit_*/pallas_* counters record dispatch topology (how many device
     # programs ran), which legitimately differs between a sharded run and
-    # its single-process baseline, and jit_cache_misses mirrors the
-    # process-wide compile cache — neither is protocol state, so they sit
-    # outside the exactness bar (traffic/clocks/protocol counters).
-    sa = {k: v for k, v in a.stats.items() if not k.startswith("jit_")}
-    sb = {k: v for k, v in b.stats.items() if not k.startswith("jit_")}
+    # its single-process baseline or between backends, and
+    # jit_cache_misses mirrors the process-wide compile cache — none is
+    # protocol state, so they sit outside the exactness bar
+    # (traffic/clocks/protocol counters).
+    dispatch = ("jit_", "pallas_")
+    sa = {k: v for k, v in a.stats.items() if not k.startswith(dispatch)}
+    sb = {k: v for k, v in b.stats.items() if not k.startswith(dispatch)}
     assert sa == sb, (ctx, sa, sb)

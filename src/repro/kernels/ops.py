@@ -1,8 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on real TPU
-— the kernel *code* is identical; interpret mode executes the same kernel
-body with pure-JAX semantics for validation.
+``interpret`` defaults to ``platform.pallas_interpret()``: True on the CPU
+backend and False on a TPU — the kernel *code* is identical; interpret mode
+executes the same kernel body with pure-JAX semantics for validation.
 """
 from __future__ import annotations
 
@@ -14,10 +14,7 @@ import jax.numpy as jnp
 from repro.kernels import flash_attention as _fa
 from repro.kernels import page_diff as _pd
 from repro.kernels import ssd_chunk as _sc
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.platform import pallas_interpret as _default_interpret
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

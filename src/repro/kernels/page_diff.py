@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 PAGES_PER_BLOCK = 8
+_LANE = 128        # per-page counts are written lane-broadcast (TPU tiling)
 
 
 def _diff_encode_kernel(curr_ref, twin_ref, mask_ref, vals_ref, count_ref):
@@ -37,7 +38,8 @@ def _diff_encode_kernel(curr_ref, twin_ref, mask_ref, vals_ref, count_ref):
         jax.lax.bitcast_convert_type(twin, jnp.int32)
     mask_ref[...] = changed.astype(jnp.int8)
     vals_ref[...] = jnp.where(changed, curr, 0.0)
-    count_ref[...] = jnp.sum(changed.astype(jnp.int32), axis=1)
+    count = jnp.sum(changed.astype(jnp.int32), axis=1, keepdims=True)
+    count_ref[...] = jnp.broadcast_to(count, count_ref.shape)
 
 
 def _diff_apply_kernel(dst_ref, mask_ref, vals_ref, out_ref):
@@ -57,22 +59,23 @@ def diff_encode(curr, twin, *, interpret: bool = False):
     n, w = curr.shape
     g, ppb = _grid_for(n)
     page_spec = pl.BlockSpec((ppb, w), lambda i: (i, 0))
-    return pl.pallas_call(
+    mask, vals, count = pl.pallas_call(
         _diff_encode_kernel,
         grid=(g,),
         in_specs=[page_spec, page_spec],
         out_specs=[
             pl.BlockSpec((ppb, w), lambda i: (i, 0)),
             pl.BlockSpec((ppb, w), lambda i: (i, 0)),
-            pl.BlockSpec((ppb,), lambda i: (i,)),
+            pl.BlockSpec((ppb, _LANE), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, w), jnp.int8),
             jax.ShapeDtypeStruct((n, w), curr.dtype),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n, _LANE), jnp.int32),
         ],
         interpret=interpret,
     )(curr, twin)
+    return mask, vals, count[:, 0]
 
 
 def diff_apply(dst, mask, vals, *, interpret: bool = False):

@@ -45,6 +45,7 @@ cached (``available_backends``), not re-checked per call.
 """
 from __future__ import annotations
 
+import functools
 import os
 import warnings
 from typing import Optional, Tuple
@@ -55,6 +56,9 @@ try:
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.platform import pallas_interpret
     HAVE_PALLAS = True
 except Exception:                                  # jax absent / broken
     HAVE_PALLAS = False
@@ -112,8 +116,10 @@ def resolve_backend(backend: str) -> str:
 # jit-dispatch accounting: every fused/jitted kernel call notes itself in
 # the caller's stats dict (the runtime's ``jit_dispatches`` counter — CI
 # fails when a bench leg silently falls back to numpy and the counter
-# stays 0).  ``jit_cache_misses`` counts first-seen (kernel, shape) keys,
-# mirroring jax's process-wide compilation cache.
+# stays 0) and under ``jit_<kernel>``.  ``jit_cache_misses`` counts
+# first-seen (kernel, shape) keys, mirroring jax's process-wide
+# compilation cache.  Per-op 'pallas' calls count under ``pallas_<kernel>``
+# only, outside the gated ``jit_dispatches``.
 _JIT_SEEN: set = set()
 
 
@@ -121,9 +127,17 @@ def _note_dispatch(stats: Optional[dict], key):
     if stats is None:
         return
     stats["jit_dispatches"] = stats.get("jit_dispatches", 0) + 1
+    name = f"jit_{key[0]}"
+    stats[name] = stats.get(name, 0) + 1
     if key not in _JIT_SEEN:
         _JIT_SEEN.add(key)
         stats["jit_cache_misses"] = stats.get("jit_cache_misses", 0) + 1
+
+
+def _note_pallas(stats: Optional[dict], kernel: str):
+    if stats is not None:
+        name = f"pallas_{kernel}"
+        stats[name] = stats.get(name, 0) + 1
 
 
 # ---------------------------------------------------------------------------
@@ -220,128 +234,157 @@ def _kth_set_index_np(bits: np.ndarray, k: np.ndarray) -> np.ndarray:
 
 if HAVE_PALLAS:
 
-    def _popcount_kernel(bits_ref, out_ref):
-        v = bits_ref[...]
+    def _swar_pop_j(v):
+        """Per-word SWAR popcount of a traced uint32 array -> int32."""
         v = v - ((v >> 1) & jnp.uint32(0x55555555))
         v = ((v & jnp.uint32(0x33333333))
              + ((v >> 2) & jnp.uint32(0x33333333)))
         v = (v + (v >> 4)) & jnp.uint32(0x0F0F0F0F)
-        v = (v * jnp.uint32(0x01010101)) >> 24
-        out_ref[...] = jnp.sum(v.astype(jnp.int32), axis=1)
+        return ((v * jnp.uint32(0x01010101)) >> 24).astype(jnp.int32)
 
-    def _popcount_rows_pallas(bits: np.ndarray) -> np.ndarray:
-        W, n_words = bits.shape
-        Wp = -(-W // ROWS_PER_BLOCK) * ROWS_PER_BLOCK
-        Cp = max(-(-n_words // _LANE) * _LANE, _LANE)
-        padded = np.zeros((Wp, Cp), np.uint32)     # zero words add 0 bits
-        padded[:W, :n_words] = bits
-        out = pl.pallas_call(
-            _popcount_kernel,
-            grid=(Wp // ROWS_PER_BLOCK,),
-            in_specs=[pl.BlockSpec((ROWS_PER_BLOCK, Cp), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((ROWS_PER_BLOCK,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((Wp,), jnp.int32),
-            interpret=jax.default_backend() != "tpu",
-        )(jnp.asarray(padded))
-        return np.asarray(out[:W]).astype(np.int64)
+    def _lane_cumsum(x):
+        """Exact inclusive int32 prefix sum along axis 1 inside a kernel:
+        log-step shift-and-add over lane rotations (the TPU lowering has
+        no ``cumsum``).  ``pltpu.roll`` follows ``jnp.roll``, so lane i
+        adds lane i - s; lanes below s add nothing."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        s = 1
+        while s < x.shape[1]:
+            x = x + jnp.where(lane >= s, pltpu.roll(x, s, 1), 0)
+            s *= 2
+        return x
+
+    def _popcount_kernel(bits_ref, out_ref):
+        n = jnp.sum(_swar_pop_j(bits_ref[...]), axis=1, keepdims=True)
+        out_ref[...] = jnp.broadcast_to(n, out_ref.shape)
 
     def _take_first_k_kernel(bits_ref, k_ref, out_ref):
         v = bits_ref[...]
-        pc = v - ((v >> 1) & jnp.uint32(0x55555555))
-        pc = ((pc & jnp.uint32(0x33333333))
-              + ((pc >> 2) & jnp.uint32(0x33333333)))
-        pc = (pc + (pc >> 4)) & jnp.uint32(0x0F0F0F0F)
-        pc = (pc * jnp.uint32(0x01010101)) >> 24
-        excl = jnp.cumsum(pc.astype(jnp.int32), axis=1) - pc.astype(jnp.int32)
-        need = jnp.clip(k_ref[...] - excl, 0, 32).astype(jnp.uint32)
+        pc = _swar_pop_j(v)
+        need = jnp.clip(k_ref[...] - (_lane_cumsum(pc) - pc), 0, 32)
         out = jnp.zeros_like(v)
-        run = jnp.zeros_like(v)
+        run = jnp.zeros_like(pc)
         for j in range(32):                      # static rank-select steps
-            bit = (v >> j) & jnp.uint32(1)
+            bit = ((v >> j) & jnp.uint32(1)).astype(jnp.int32)
             sel = (bit != 0) & (run < need)
             out = out | (sel.astype(jnp.uint32) << j)
             run = run + bit
         out_ref[...] = out
 
-    def _take_first_k_pallas(bits: np.ndarray, k: np.ndarray) -> np.ndarray:
-        R, n_words = bits.shape
-        Rp = -(-R // ROWS_PER_BLOCK) * ROWS_PER_BLOCK
-        Cp = max(-(-n_words // _LANE) * _LANE, _LANE)
-        padded = np.zeros((Rp, Cp), np.uint32)
-        padded[:R, :n_words] = bits
-        kp = np.zeros((Rp, 1), np.int32)
-        kp[:R, 0] = np.minimum(k, np.iinfo(np.int32).max)
-        out = pl.pallas_call(
+    def _kth_set_index_kernel(bits_ref, k_ref, out_ref):
+        v = bits_ref[...]
+        pc = _swar_pop_j(v)
+        k = k_ref[...]                                     # (rows, 1)
+        # cumulative popcounts never decrease, so the words that end
+        # before the k-th set bit form a prefix: its length is the word
+        # index and its popcount the bits before that word
+        before = _lane_cumsum(pc) < k
+        wi = jnp.sum(before.astype(jnp.int32), axis=1, keepdims=True)
+        excl = jnp.sum(jnp.where(before, pc, 0), axis=1, keepdims=True)
+        total = jnp.sum(pc, axis=1, keepdims=True)
+        lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+        word = jnp.sum(jnp.where(lane == wi, pltpu.bitcast(v, jnp.int32), 0),
+                       axis=1, keepdims=True)        # one nonzero term
+        need = k - excl
+        run = jnp.zeros_like(need)
+        idx = jnp.full_like(need, -1)
+        for j in range(32):                  # static rank steps
+            bit = (word >> j) & 1
+            run = run + bit
+            hit = (idx < 0) & (bit == 1) & (run == need)
+            idx = jnp.where(hit, 32 * wi + j, idx)
+        ok = (k >= 1) & (total >= k)
+        out_ref[...] = jnp.broadcast_to(jnp.where(ok, idx, -1), out_ref.shape)
+
+    def _coverage_kernel(delta_ref, multi_ref):
+        multi_ref[...] = (_lane_cumsum(delta_ref[...]) >= 2).astype(jnp.int32)
+
+    # Each ``*_call`` builds the jitted pallas_call for padded shapes:
+    # rows in blocks of ROWS_PER_BLOCK, columns padded to whole lanes,
+    # per-row scalars written lane-broadcast to a (rows, _LANE) block
+    # (the host keeps lane 0).  Built once per shape, so repeat calls hit
+    # jax's in-memory compile cache.  ``tests/test_tpu_compile.py``
+    # compiles these for a described TPU.
+
+    @functools.lru_cache(maxsize=64)
+    def _popcount_call(Rp: int, Cp: int, interpret: bool):
+        return jax.jit(pl.pallas_call(
+            _popcount_kernel,
+            grid=(Rp // ROWS_PER_BLOCK,),
+            in_specs=[pl.BlockSpec((ROWS_PER_BLOCK, Cp), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((ROWS_PER_BLOCK, _LANE), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((Rp, _LANE), jnp.int32),
+            interpret=interpret))
+
+    @functools.lru_cache(maxsize=64)
+    def _take_first_k_call(Rp: int, Cp: int, interpret: bool):
+        return jax.jit(pl.pallas_call(
             _take_first_k_kernel,
             grid=(Rp // ROWS_PER_BLOCK,),
             in_specs=[pl.BlockSpec((ROWS_PER_BLOCK, Cp), lambda i: (i, 0)),
                       pl.BlockSpec((ROWS_PER_BLOCK, 1), lambda i: (i, 0))],
             out_specs=pl.BlockSpec((ROWS_PER_BLOCK, Cp), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((Rp, Cp), jnp.uint32),
-            interpret=jax.default_backend() != "tpu",
-        )(jnp.asarray(padded), jnp.asarray(kp))
-        return np.asarray(out[:R, :n_words])
+            interpret=interpret))
 
-    def _kth_set_index_kernel(bits_ref, k_ref, out_ref):
-        v = bits_ref[...]
-        pc = v - ((v >> 1) & jnp.uint32(0x55555555))
-        pc = ((pc & jnp.uint32(0x33333333))
-              + ((pc >> 2) & jnp.uint32(0x33333333)))
-        pc = (pc + (pc >> 4)) & jnp.uint32(0x0F0F0F0F)
-        pc = ((pc * jnp.uint32(0x01010101)) >> 24).astype(jnp.int32)
-        cum = jnp.cumsum(pc, axis=1)
-        total = cum[:, -1:]
-        k = k_ref[...]
-        reach = cum >= k
-        wi = jnp.argmax(reach, axis=1, keepdims=True)
-        excl = jnp.take_along_axis(cum - pc, wi, axis=1)
-        need = k - excl
-        word = jnp.take_along_axis(v, wi, axis=1)
-        run = jnp.zeros_like(need)
-        idx = jnp.full_like(need, -1)
-        for j in range(32):                  # static rank steps
-            bit = ((word >> j) & jnp.uint32(1)).astype(jnp.int32)
-            run = run + bit
-            hit = (idx < 0) & (bit == 1) & (run == need)
-            idx = jnp.where(hit, 32 * wi + j, idx)
-        ok = (k >= 1) & (total >= k)
-        out_ref[...] = jnp.where(ok, idx, -1)
+    @functools.lru_cache(maxsize=64)
+    def _kth_set_index_call(Rp: int, Cp: int, interpret: bool):
+        return jax.jit(pl.pallas_call(
+            _kth_set_index_kernel,
+            grid=(Rp // ROWS_PER_BLOCK,),
+            in_specs=[pl.BlockSpec((ROWS_PER_BLOCK, Cp), lambda i: (i, 0)),
+                      pl.BlockSpec((ROWS_PER_BLOCK, 1), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((ROWS_PER_BLOCK, _LANE), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((Rp, _LANE), jnp.int32),
+            interpret=interpret))
 
-    def _kth_set_index_pallas(bits: np.ndarray, k: np.ndarray) -> np.ndarray:
+    @functools.lru_cache(maxsize=64)
+    def _coverage_call(npad: int, interpret: bool):
+        return jax.jit(pl.pallas_call(
+            _coverage_kernel,
+            in_specs=[pl.BlockSpec((1, npad), lambda: (0, 0))],
+            out_specs=pl.BlockSpec((1, npad), lambda: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((1, npad), jnp.int32),
+            interpret=interpret))
+
+    def _pad_rows(bits: np.ndarray, k=None):
+        """Zero-pad (R, n_words) to whole row blocks and lanes (zero words
+        add no set bits); ``k`` pads to an int32 (Rp, 1) column."""
         R, n_words = bits.shape
         Rp = -(-R // ROWS_PER_BLOCK) * ROWS_PER_BLOCK
         Cp = max(-(-n_words // _LANE) * _LANE, _LANE)
         padded = np.zeros((Rp, Cp), np.uint32)
         padded[:R, :n_words] = bits
+        if k is None:
+            return padded
         kp = np.zeros((Rp, 1), np.int32)
-        kp[:R, 0] = np.minimum(k, np.iinfo(np.int32).max)
-        out = pl.pallas_call(
-            _kth_set_index_kernel,
-            grid=(Rp // ROWS_PER_BLOCK,),
-            in_specs=[pl.BlockSpec((ROWS_PER_BLOCK, Cp), lambda i: (i, 0)),
-                      pl.BlockSpec((ROWS_PER_BLOCK, 1), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((ROWS_PER_BLOCK, 1), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((Rp, 1), jnp.int32),
-            interpret=jax.default_backend() != "tpu",
-        )(jnp.asarray(padded), jnp.asarray(kp))
-        return np.asarray(out[:R, 0]).astype(np.int64)
+        kp[:R, 0] = _k32(k)
+        return padded, kp
 
-    def _coverage_kernel(delta_ref, multi_ref):
-        cover = jnp.cumsum(delta_ref[...], axis=1)
-        multi_ref[...] = (cover >= 2).astype(jnp.int8)
+    def _popcount_rows_pallas(bits: np.ndarray) -> np.ndarray:
+        padded = _pad_rows(bits)
+        out = _popcount_call(*padded.shape, pallas_interpret())(
+            jnp.asarray(padded))
+        return np.asarray(out[:bits.shape[0], 0]).astype(np.int64)
+
+    def _take_first_k_pallas(bits: np.ndarray, k: np.ndarray) -> np.ndarray:
+        padded, kp = _pad_rows(bits, k)
+        out = _take_first_k_call(*padded.shape, pallas_interpret())(
+            jnp.asarray(padded), jnp.asarray(kp))
+        return np.asarray(out[:bits.shape[0], :bits.shape[1]])
+
+    def _kth_set_index_pallas(bits: np.ndarray, k: np.ndarray) -> np.ndarray:
+        padded, kp = _pad_rows(bits, k)
+        out = _kth_set_index_call(*padded.shape, pallas_interpret())(
+            jnp.asarray(padded), jnp.asarray(kp))
+        return np.asarray(out[:bits.shape[0], 0]).astype(np.int64)
 
     def _coverage_multi_pallas(delta: np.ndarray) -> np.ndarray:
         n = delta.size
         npad = max(-(-n // _LANE) * _LANE, _LANE)
         padded = np.zeros((1, npad), np.int32)
         padded[0, :n] = delta
-        out = pl.pallas_call(
-            _coverage_kernel,
-            in_specs=[pl.BlockSpec((1, npad), lambda: (0, 0))],
-            out_specs=pl.BlockSpec((1, npad), lambda: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((1, npad), jnp.int8),
-            interpret=jax.default_backend() != "tpu",
-        )(jnp.asarray(padded))
+        out = _coverage_call(npad, pallas_interpret())(jnp.asarray(padded))
         return np.asarray(out[0, :n]).astype(bool)
 
     # -----------------------------------------------------------------
@@ -350,13 +393,6 @@ if HAVE_PALLAS:
     # fused chains run several protocol ops per dispatch with the packed
     # planes staying device-resident in between.
     # -----------------------------------------------------------------
-
-    def _swar_pop_j(v):
-        v = v - ((v >> 1) & jnp.uint32(0x55555555))
-        v = ((v & jnp.uint32(0x33333333))
-             + ((v >> 2) & jnp.uint32(0x33333333)))
-        v = (v + (v >> 4)) & jnp.uint32(0x0F0F0F0F)
-        return ((v * jnp.uint32(0x01010101)) >> 24).astype(jnp.int32)
 
     def _rank_select_j(bits, k):
         """Packed per-row rank-select (first k[i] set bits), traced: the
@@ -486,6 +522,7 @@ def popcount_rows(bits: np.ndarray, *, backend: str = "numpy",
         _note_dispatch(stats, ("popcount", bits.shape))
         return out.astype(np.int64)
     if b == "pallas":
+        _note_pallas(stats, "popcount")
         return _popcount_rows_pallas(bits)
     return _popcount_rows_np(bits)
 
@@ -505,6 +542,7 @@ def take_first_k(bits: np.ndarray, k: np.ndarray, *,
         _note_dispatch(stats, ("take_first_k", bits.shape))
         return out
     if b == "pallas":
+        _note_pallas(stats, "take_first_k")
         return _take_first_k_pallas(bits, k)
     return _take_first_k_np(bits, np.asarray(k, np.int64))
 
@@ -524,6 +562,7 @@ def kth_set_index(bits: np.ndarray, k: np.ndarray, *,
         _note_dispatch(stats, ("kth_set_index", bits.shape))
         return out.astype(np.int64)
     if b == "pallas":
+        _note_pallas(stats, "kth_set_index")
         return _kth_set_index_pallas(bits, np.asarray(k, np.int64))
     return _kth_set_index_np(bits, np.asarray(k, np.int64))
 
@@ -539,6 +578,7 @@ def coverage_multi(delta: np.ndarray, *, backend: str = "numpy",
         _note_dispatch(stats, ("coverage", delta.shape))
         return out
     if b == "pallas":
+        _note_pallas(stats, "coverage")
         return _coverage_multi_pallas(delta.astype(np.int32))
     return np.cumsum(delta) >= 2
 
@@ -562,6 +602,8 @@ def take_and_cut(bits: np.ndarray, k: np.ndarray, *,
         return np.asarray(take), np.asarray(cut).astype(np.int64)
     kk = np.asarray(k, np.int64)
     if b == "pallas":
+        _note_pallas(stats, "take_first_k")
+        _note_pallas(stats, "kth_set_index")
         return (_take_first_k_pallas(bits, kk),
                 _kth_set_index_pallas(bits, kk))
     return _take_first_k_np(bits, kk), _kth_set_index_np(bits, kk)

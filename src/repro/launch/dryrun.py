@@ -1,9 +1,13 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# NOTE: the two lines above MUST run before any other import (including jax
-# and repro.*): jax locks the device count at first init.  This flag is set
-# ONLY here — tests and benchmarks see the real single CPU device.
+# NOTE: the lines above MUST run before any other import (including jax
+# and repro.*): jax locks the device count at first init.  The dry run
+# lowers for 512 placeholder CPU devices by design, so it is pinned to the
+# CPU and never reaches for an accelerator its parent may hold.  These
+# flags are set ONLY here — tests and benchmarks see the real single CPU
+# device.
 
 import argparse        # noqa: E402
 import dataclasses     # noqa: E402
@@ -61,8 +65,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes,
         }
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):     # jax <= 0.4.x: one dict per program
-            ca = ca[0] if ca else {}
         rec["xla_cost_analysis"] = {
             k: v for k, v in ca.items()
             if k in ("flops", "bytes accessed") and v == v}

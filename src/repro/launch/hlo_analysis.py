@@ -17,7 +17,8 @@ module re-derives the three roofline terms from ``compiled.as_text()``:
 * bytes = sum over *top-level* ops of (operand + output bytes) — the text is
   post-fusion, so a fusion counts once with its true inputs/outputs,
 * collective bytes are summed per opcode over {all-reduce, all-gather,
-  reduce-scatter, all-to-all, collective-permute} using operand sizes.
+  reduce-scatter, all-to-all, collective-permute} using operand sizes; the
+  TPU's async pairs count once, at ``<collective>-start``.
 """
 from __future__ import annotations
 
@@ -44,6 +45,16 @@ _OPERAND_RE = re.compile(r"%([\w\.\-]+)")
 
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute")
+
+
+def _async_payload_bytes(type_str: str, operand_bytes: int) -> int:
+    """Payload of a ``<collective>-start``: its output tuple repeats the
+    operands (collective-permute, all-gather) ahead of the results and ends
+    in ``u32[]`` context scalars, which carry no data."""
+    out = sum(shape_bytes(f"{dt}[{dims}]")
+              for dt, dims in _SHAPE_RE.findall(type_str)
+              if (dt, dims) != ("u32", ""))
+    return max(operand_bytes, out - operand_bytes)
 
 
 def shape_bytes(type_str: str) -> int:
@@ -282,4 +293,9 @@ def analyze(text: str) -> HloStats:
             if op.opcode in COLLECTIVES:
                 stats.collective_bytes[op.opcode] += m * max(ib, ob)
                 stats.collective_count[op.opcode] += m
+            elif op.opcode.endswith("-start") and op.opcode[:-6] in COLLECTIVES:
+                coll = op.opcode[:-6]
+                stats.collective_bytes[coll] += m * _async_payload_bytes(
+                    op.out_type, ib)
+                stats.collective_count[coll] += m
     return stats

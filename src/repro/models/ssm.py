@@ -167,19 +167,10 @@ def mamba2_block(params, x, cfg, ctx: Optional[ShardingCtx], *,
     Cr = jnp.einsum("bsd,de->bse", x, params["in_C"])
     dtr = jnp.einsum("bsd,dh->bsh", x, params["in_dt"])
 
-    # the packed x/B/C conv activation must stay replicated on its feature
-    # dim: the concat/split boundaries (d_in, d_in+gn) don't align with a
-    # 'model' sharding of the packed dim, and GSPMD (jax 0.4.37) miscompiles
-    # the straddling concat/split when the batch dim is replicated
-    # (DECODE_2D_RULES) — wrong VALUES, not just extra collectives.  xh
-    # re-shards over 'ssm_in' right after the split, so TP sharding of the
-    # SSD math is unaffected; the replicated tensor is only (B, S, conv_dim).
     conv_in = jnp.concatenate([xr, Br, Cr], axis=-1)
-    conv_in = constrain(conv_in, ("batch", "seq", None), ctx)
     tail_in = cache[0] if (cache is not None) else None
     conv_out, new_tail = _causal_conv(conv_in, params["conv_w"], params["conv_b"],
                                       tail=tail_in)
-    conv_out = constrain(conv_out, ("batch", "seq", None), ctx)
     xr, Br, Cr = jnp.split(conv_out, [d_in, d_in + gn], axis=-1)
 
     xh = xr.reshape(B, S, H, P)

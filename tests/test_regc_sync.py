@@ -116,6 +116,28 @@ def test_policy_validation():
         RegCSyncPolicy(granularity="page")
 
 
+_PERMUTE_HLO = """ENTRY %main (p: s8[128]) -> s8[128] {
+  %p = s8[128]{0} parameter(0)
+  ROOT %cp = s8[128]{0} collective-permute(%p), source_target_pairs={{0,1}}
+}"""
+# the TPU's async form of the same permute: the start's output tuple holds
+# the operand, the result and two context scalars
+_PERMUTE_ASYNC_HLO = """ENTRY %main (p: s8[128]) -> s8[128] {
+  %p = s8[128]{0} parameter(0)
+  %cps = (s8[128]{0}, s8[128]{0}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%p), source_target_pairs={{0,1}}
+  ROOT %cpd = s8[128]{0} collective-permute-done(%cps)
+}"""
+
+
+@pytest.mark.parametrize("text", [_PERMUTE_HLO, _PERMUTE_ASYNC_HLO],
+                         ids=["sync", "async"])
+def test_hlo_collective_bytes(text):
+    from repro.launch import hlo_analysis
+    st = hlo_analysis.analyze(text)
+    assert st.collective_count["collective-permute"] == 1
+    assert st.total_collective_bytes == 128
+
+
 # ---------------------------------------------------------------------------
 # multi-device semantics (subprocess, 8 fake host devices)
 # ---------------------------------------------------------------------------
