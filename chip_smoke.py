@@ -101,37 +101,17 @@ def preflight(n_chips: int):
     return devices
 
 
-class CompileEvents:
-    """JAX's own compile and persistent-cache events, process-wide."""
+def events_now(events):
+    """A ``chipbench.harness.CompileEvents`` reading, for ``since``."""
+    return (events.hits, events.misses, events.compiles, events.compile_s)
 
-    def __init__(self):
-        import jax
-        from jax._src.dispatch import BACKEND_COMPILE_EVENT
-        self.hits = self.misses = self.compiles = 0
-        self.compile_s = 0.0
-        self._compile_event = BACKEND_COMPILE_EVENT
-        jax.monitoring.register_event_listener(self._event)
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
 
-    def _event(self, name, **_):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif name == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def _duration(self, name, secs, **_):
-        if name == self._compile_event:
-            self.compiles += 1
-            self.compile_s += secs
-
-    def snapshot(self):
-        return (self.hits, self.misses, self.compiles, self.compile_s)
-
-    def since(self, snap):
-        h, m, c, s = snap
-        return {"cache_hits": self.hits - h, "cache_misses": self.misses - m,
-                "backend_compiles": self.compiles - c,
-                "backend_compile_s": self.compile_s - s}
+def since(events, snap):
+    h, m, c, s = snap
+    return {"cache_hits": events.hits - h,
+            "cache_misses": events.misses - m,
+            "backend_compiles": events.compiles - c,
+            "backend_compile_s": events.compile_s - s}
 
 
 def peak_bytes(device):
@@ -166,7 +146,7 @@ def kernel_oracles(device, events):
             "kth_set_index": ps.kth_set_index(bits, k),
             "coverage": ps.coverage_multi(delta)}
     rec = {"phase": "kernels", "W": W, "window_pages": nw * 32}
-    snap = events.snapshot()
+    snap = events_now(events)
     t0 = time.perf_counter()
     for backend in ("pallas", "pallas-jit"):
         st = {}
@@ -217,7 +197,7 @@ def kernel_oracles(device, events):
           and np.array_equal(np.asarray(vals), np.where(changed, curr, 0)),
           "kernels: diff_encode differs from numpy")
     rec["wall_s"] = time.perf_counter() - t0
-    rec.update(events.since(snap))
+    rec.update(since(events, snap))
     rec["peak_bytes_in_use"] = peak_bytes(device)
     emit(rec)
 
@@ -288,7 +268,7 @@ def run_protocol_phase(backend, phase, device, events):
               f"{phase.name}: asked for backend {backend!r}, the runtime "
               f"resolved {rt.backend!r}")
         flushes = count_dirty_flushes(rt)
-        snap = events.snapshot()
+        snap = events_now(events)
         t0 = time.perf_counter()
         rep = phase.run(rt)
         wall = time.perf_counter() - t0
@@ -317,7 +297,7 @@ def run_protocol_phase(backend, phase, device, events):
                   and sum(calls.values()) > 0,
                   f"{tag}: no per-op pallas kernel ran: {calls}")
         rec[leg] = {"wall_s": wall, "dirty_flushes": flushes["flushes"],
-                    "calls": calls, **events.since(snap)}
+                    "calls": calls, **since(events, snap)}
     rec["peak_bytes_in_use"] = peak_bytes(device)
     emit(rec)
 
@@ -415,7 +395,7 @@ def sync_phase(cfg, devices, batch, seq, events, *, step_index=100):
         if p_old is None:
             p_old = host_tree(params)
             n_params = sum(x.size for x in p_old)
-        snap = events.snapshot()
+        snap = events_now(events)
         t0 = time.perf_counter()
         compiled = fn.lower(params, opt, data, step0).compile()
         compile_s = time.perf_counter() - t0
@@ -445,7 +425,7 @@ def sync_phase(cfg, devices, batch, seq, events, *, step_index=100):
               "compile_s": compile_s, "first_step_s": first_s,
               "steady_step_s": steady_s,
               "peak_bytes_in_use": peak_bytes(devices[0]),
-              **events.since(snap)})
+              **since(events, snap)})
         del params, opt, data, compiled
     ref_p, ref_g, ref_m = results["gspmd"]
     # From fresh moments AdamW moves each element by about +-lr whatever
@@ -514,6 +494,7 @@ def main(argv=None):
                          "mesh (default: 1, the protocol phases)")
     args = ap.parse_args(argv)
     devices = preflight(args.chips)
+    from chipbench.harness import CompileEvents
     events = CompileEvents()
     t0 = time.perf_counter()
     if args.chips == 4:

@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import functools
 import re
 from collections import deque
 from typing import Dict, List, Optional, Tuple
@@ -65,6 +66,23 @@ from repro.core.directory import IntervalLog, RegionDirectory, use_dense
 from repro.core.regc import (FINE_PROTO, IDEAL_PROTO, PAGE_PROTO, GasArray,
                              Traffic, _WORD)
 from repro.dsm.costmodel import CostModel, IB_2013
+from repro.utils.trace import span
+
+
+def _spanned(name: str, at: bool = False):
+    """Run the method inside the span ``name``.  With ``at`` the span
+    carries the phase-program position that the call's ``chaos_tick``
+    takes, so a slow phase, span pass or barrier can be named in a trace."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(self, *args, **kwargs):
+            if at:
+                with span(name, at=self._phase_idx + 1):
+                    return fn(self, *args, **kwargs)
+            with span(name):
+                return fn(self, *args, **kwargs)
+        return call
+    return wrap
 
 
 class _Span:
@@ -1179,6 +1197,7 @@ class RegCScaleRuntime:
             self._invalidate_sharers(w, region, d.base[w] + cols)
         regions.clear()
 
+    @_spanned("regc.flush")
     def _flush_all_workers(self, mask: Optional[np.ndarray] = None):
         """Batched flush of every (masked) worker's ordinary-dirty pages,
         in one pass per region that reproduces the sequential flush-order
@@ -1208,11 +1227,21 @@ class RegCScaleRuntime:
         # unfused path by construction.  IDEAL skips sharer work entirely
         # and keeps the short-circuit path.
         jit_counts = jit_shared = None
-        ji = 0
         if self.backend == "pallas-jit" and self.protocol != IDEAL_PROTO:
             cand = [d for d in self.dirs if d.maybe_dirty and d.cap > 0]
             if cand:
                 jit_counts, jit_shared = self._jit_flush_chain(cand, mask)
+        with span("regc.flush.apply"):
+            self._flush_apply(mask, mrows, jit_counts, jit_shared)
+
+    def _flush_apply(self, mask: Optional[np.ndarray],
+                     mrows: Optional[np.ndarray], jit_counts, jit_shared):
+        """The host half of ``_flush_all_workers``: each dirty region's
+        writeback charge, wprot re-arm, sharer invalidation and dirty-plane
+        clear, from the fused chain's outputs where it ran (``jit_counts``
+        and ``jit_shared``, in ``self.dirs`` order) and by the host sweep
+        elsewhere."""
+        ji = 0
         for d in self.dirs:
             if not d.maybe_dirty:
                 continue
@@ -1321,19 +1350,20 @@ class RegCScaleRuntime:
                 self.stats.get("jit_flush_fallbacks", 0) + 1)
             return None, None
         i32max = np.iinfo(np.int32).max
-        bits = np.zeros((R, W, nw_max), np.uint32)
-        base32 = np.empty((R, W), np.int32)
-        sbs = np.full((R, W), i32max, np.int32)
-        ses = np.full((R, W), i32max, np.int32)
-        for i, d in enumerate(cand):
-            pk = _ps.pack_mask_rows(d.dirty)
-            bits[i, :, :pk.shape[1]] = pk
-            b32, sb, se = d.jit_geometry()
-            base32[i] = b32
-            sbs[i, :sb.size] = sb
-            ses[i, :se.size] = se
-        rowmask = (np.ones((R, W), bool) if mask is None
-                   else np.broadcast_to(mask, (R, W)))
+        with span("regc.flush.pack", regions=R, words=nw_max):
+            bits = np.zeros((R, W, nw_max), np.uint32)
+            base32 = np.empty((R, W), np.int32)
+            sbs = np.full((R, W), i32max, np.int32)
+            ses = np.full((R, W), i32max, np.int32)
+            for i, d in enumerate(cand):
+                pk = _ps.pack_mask_rows(d.dirty)
+                bits[i, :, :pk.shape[1]] = pk
+                b32, sb, se = d.jit_geometry()
+                base32[i] = b32
+                sbs[i, :sb.size] = sb
+                ses[i, :se.size] = se
+            rowmask = (np.ones((R, W), bool) if mask is None
+                       else np.broadcast_to(mask, (R, W)))
         counts, shared = _ps.phase_step(bits, base32, rowmask, sbs, ses,
                                         stats=self.stats)
         return counts, shared
@@ -1844,6 +1874,12 @@ class RegCScaleRuntime:
             return
         rows = rows[over]
         k = k[over].astype(np.int64)
+        with span("regc.evict", rows=int(rows.size)):
+            self._evict_rounds(rows, k)
+
+    def _evict_rounds(self, rows: np.ndarray, k: np.ndarray):
+        """``_evict_rows_batch``'s rounds: evict ``k[i]`` pages of worker
+        ``rows[i]`` (ascending rows), a front run per worker per round."""
         charge = self.protocol != IDEAL_PROTO
         while rows.size:
             if rows.size < 4:
@@ -2168,6 +2204,7 @@ class RegCScaleRuntime:
         d.valid[rb, s] = True
         d.dirty[rb, s] = True
 
+    @_spanned("regc.phase", at=True)
     def phase_all(self, reads=(), writes=(), *, flops=0.0, mem_bytes=0.0,
                   seconds=0.0, instr_words=0.0):
         """One SPMD phase for ALL workers in a single runtime call.
@@ -2649,6 +2686,7 @@ class RegCScaleRuntime:
         IC[:, sl] = True
         self.resident[grp] += enters
 
+    @_spanned("regc.span", at=True)
     def span_all(self, w_mask=None, lock_ids=0, reads=(), writes=()):
         """One consistency-region pass for many workers in a single call.
 
@@ -2754,6 +2792,7 @@ class RegCScaleRuntime:
     def reduction_result(self, name: str) -> float:
         return self._reduction_results[name]
 
+    @_spanned("regc.barrier", at=True)
     def barrier(self):
         self.chaos_tick()
         self._flush_all_workers()

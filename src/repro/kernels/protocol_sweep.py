@@ -52,6 +52,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro.utils.trace import span
+
 try:
     import jax
     import jax.numpy as jnp
@@ -118,20 +120,42 @@ def resolve_backend(backend: str) -> str:
 # fails when a bench leg silently falls back to numpy and the counter
 # stays 0) and under ``jit_<kernel>``.  ``jit_cache_misses`` counts
 # first-seen (kernel, shape) keys, mirroring jax's process-wide
-# compilation cache.  Per-op 'pallas' calls count under ``pallas_<kernel>``
+# compilation cache.  ``jit_h2d_bytes``/``jit_d2h_bytes`` count the bytes
+# of the arrays a dispatch copies to the device and back: copies, not
+# their time.  Per-op 'pallas' calls count under ``pallas_<kernel>``
 # only, outside the gated ``jit_dispatches``.
 _JIT_SEEN: set = set()
 
 
-def _note_dispatch(stats: Optional[dict], key):
+def _note_dispatch(stats: Optional[dict], key, h2d_bytes: int,
+                   d2h_bytes: int):
     if stats is None:
         return
     stats["jit_dispatches"] = stats.get("jit_dispatches", 0) + 1
     name = f"jit_{key[0]}"
     stats[name] = stats.get(name, 0) + 1
+    stats["jit_h2d_bytes"] = stats.get("jit_h2d_bytes", 0) + h2d_bytes
+    stats["jit_d2h_bytes"] = stats.get("jit_d2h_bytes", 0) + d2h_bytes
     if key not in _JIT_SEEN:
         _JIT_SEEN.add(key)
         stats["jit_cache_misses"] = stats.get("jit_cache_misses", 0) + 1
+
+
+def _dispatch(kernel: str, fn, args: tuple, stats: Optional[dict]
+              ) -> tuple:
+    """One jitted dispatch of ``kernel``: the host arrays ``args`` copied
+    to the device, ``fn`` run on them and every output copied back, all
+    inside the span ``kernel.<kernel>``.  Notes the dispatch and the bytes
+    copied each way in ``stats``; returns the outputs as numpy arrays."""
+    shape = args[0].shape
+    with span(f"kernel.{kernel}", shape=shape):
+        dev = [jnp.asarray(a) for a in args]
+        out = fn(*dev)
+        host = tuple(np.asarray(o) for o in
+                     (out if isinstance(out, tuple) else (out,)))
+    _note_dispatch(stats, (kernel, shape), sum(a.nbytes for a in dev),
+                   sum(h.nbytes for h in host))
+    return host
 
 
 def _note_pallas(stats: Optional[dict], kernel: str):
@@ -518,8 +542,7 @@ def popcount_rows(bits: np.ndarray, *, backend: str = "numpy",
         return np.zeros(bits.shape[0], np.int64)
     b = resolve_backend(backend)
     if b == "pallas-jit":
-        out = np.asarray(_popcount_rows_jit(jnp.asarray(bits)))
-        _note_dispatch(stats, ("popcount", bits.shape))
+        (out,) = _dispatch("popcount", _popcount_rows_jit, (bits,), stats)
         return out.astype(np.int64)
     if b == "pallas":
         _note_pallas(stats, "popcount")
@@ -537,9 +560,8 @@ def take_first_k(bits: np.ndarray, k: np.ndarray, *,
         return np.zeros_like(bits, np.uint32)
     b = resolve_backend(backend)
     if b == "pallas-jit":
-        out = np.asarray(_take_first_k_jit(jnp.asarray(bits),
-                                           jnp.asarray(_k32(k))))
-        _note_dispatch(stats, ("take_first_k", bits.shape))
+        (out,) = _dispatch("take_first_k", _take_first_k_jit,
+                           (bits, _k32(k)), stats)
         return out
     if b == "pallas":
         _note_pallas(stats, "take_first_k")
@@ -557,9 +579,8 @@ def kth_set_index(bits: np.ndarray, k: np.ndarray, *,
         return np.full(bits.shape[0], -1, np.int64)
     b = resolve_backend(backend)
     if b == "pallas-jit":
-        out = np.asarray(_kth_set_index_jit(jnp.asarray(bits),
-                                            jnp.asarray(_k32(k))))
-        _note_dispatch(stats, ("kth_set_index", bits.shape))
+        (out,) = _dispatch("kth_set_index", _kth_set_index_jit,
+                           (bits, _k32(k)), stats)
         return out.astype(np.int64)
     if b == "pallas":
         _note_pallas(stats, "kth_set_index")
@@ -573,9 +594,8 @@ def coverage_multi(delta: np.ndarray, *, backend: str = "numpy",
     mask of sweep points where the running cover count is >= 2."""
     b = resolve_backend(backend)
     if b == "pallas-jit":
-        out = np.asarray(_coverage_multi_jit(
-            jnp.asarray(delta.astype(np.int32))))
-        _note_dispatch(stats, ("coverage", delta.shape))
+        (out,) = _dispatch("coverage", _coverage_multi_jit,
+                           (delta.astype(np.int32),), stats)
         return out
     if b == "pallas":
         _note_pallas(stats, "coverage")
@@ -596,10 +616,9 @@ def take_and_cut(bits: np.ndarray, k: np.ndarray, *,
                 np.full(bits.shape[0], -1, np.int64))
     b = resolve_backend(backend)
     if b == "pallas-jit":
-        take, cut = _take_and_cut_jit(jnp.asarray(bits),
-                                      jnp.asarray(_k32(k)))
-        _note_dispatch(stats, ("take_and_cut", bits.shape))
-        return np.asarray(take), np.asarray(cut).astype(np.int64)
+        take, cut = _dispatch("take_and_cut", _take_and_cut_jit,
+                              (bits, _k32(k)), stats)
+        return take, cut.astype(np.int64)
     kk = np.asarray(k, np.int64)
     if b == "pallas":
         _note_pallas(stats, "take_first_k")
@@ -621,11 +640,10 @@ def phase_step(bits: np.ndarray, base: np.ndarray, rowmask: np.ndarray,
     tests — the runtime routes non-jit backends through the unfused
     path."""
     if resolve_backend("pallas-jit") == "pallas-jit":
-        counts, shared = _phase_step_jit(
-            jnp.asarray(bits), jnp.asarray(base), jnp.asarray(rowmask),
-            jnp.asarray(sbases), jnp.asarray(sends))
-        _note_dispatch(stats, ("phase_step", bits.shape))
-        return np.asarray(counts).astype(np.int64), np.asarray(shared)
+        counts, shared = _dispatch(
+            "phase_step", _phase_step_jit,
+            (bits, base, rowmask, sbases, sends), stats)
+        return counts.astype(np.int64), shared
     return _phase_step_np(bits, base, rowmask, sbases, sends)
 
 

@@ -1,4 +1,2 @@
-from repro.utils.tree import (
-    global_sq_norm, tree_add, tree_bytes, tree_cast, tree_scale, tree_size,
-    tree_zeros_like,
-)
+"""Small utilities: pytree helpers (``repro.utils.tree``, JAX) and the
+runtime's named spans (``repro.utils.trace``, usable without JAX)."""
