@@ -482,42 +482,76 @@ if HAVE_PALLAS:
     def _coverage_multi_jit(delta):
         return jnp.cumsum(delta) >= 2
 
+    def _flip_points_j(sb, se):
+        """Sorted pages where "covered by >= 2 windows" changes, padded
+        with INT32_MAX: page p is multi-covered iff an odd number of flip
+        points are <= p.  The 2W bound points are sorted and each is
+        judged after every bound at that point, so a window ending where
+        another starts opens no gap and no overlap; pads stab nothing.
+        Gather-free: the coverage at each point counts the bounds <= it."""
+        pts = jnp.sort(jnp.concatenate([sb, se]))
+        cov = (jnp.sum(sb[None, :] <= pts[:, None], axis=1)
+               - jnp.sum(se[None, :] <= pts[:, None], axis=1))
+        multi = cov >= 2
+        prev = jnp.concatenate([jnp.zeros((1,), bool), multi[:-1]])
+        pad = jnp.iinfo(jnp.int32).max
+        return jnp.sort(jnp.where(multi != prev, pts, pad))
+
     @jax.jit
     def _phase_step_jit(bits, base, rowmask, sbases, sends):
         """Fused barrier-flush chain over R stacked regions — ONE device
         dispatch per protocol phase, ``lax.scan`` carrying the per-region
         loop.  Per region: per-row dirty popcount (the writeback charge),
-        the shared-coverage test (a page is a sharer-invalidation
-        candidate iff covered by >= 2 live worker windows — evaluated
-        per cell as a searchsorted stab of the sorted window bounds,
-        equivalent to the numpy path's interval sweep), and the
+        the multi-coverage word mask (a page is a sharer-invalidation
+        candidate iff covered by >= 2 live worker windows), and the
         shared-dirty candidate mask (dirty ∧ multi-covered ∧ active row)
         packed back to uint32.  The packed planes never leave the device
         between the chained ops.
 
+        The multi-covered set is at most W intervals, so it is taken as
+        its <= 2W sorted flip points (``_flip_points_j``) and the mask is
+        built 32 pages at a time, never per page: a row starts all ones
+        if an odd number of flips lie at or before its first page, and
+        each flip f inside the row's range XORs ``~0 << (f - p0)`` into
+        the word starting at page p0 that holds it and all ones into
+        every later word.  The walk takes each active row's flips in
+        order, so it runs as many passes as the most flips any active
+        row's range holds (3 in a Jacobi halo geometry, 0 where no
+        windows overlap), each a gather-free pass over the (W, nw) words.
+
         bits (R, W, nw) uint32; base (R, W) int32 row window offsets
         (-1 rows have all-zero bits); rowmask (R, W) bool flush mask;
         sbases/sends (R, W) int32 sorted live window bounds padded with
-        INT32_MAX (a pad entry stabs nothing).  Returns
-        (counts (R, W) int32, shared (R, W, nw) uint32).
+        INT32_MAX (a pad entry stabs nothing); base + 32 * nw must stay
+        below INT32_MAX.  Returns (counts (R, W) int32, shared (R, W, nw)
+        uint32).
         """
-        nw = bits.shape[2]
-        col = (jnp.arange(nw, dtype=jnp.int32)[:, None] * 32
-               + jnp.arange(32, dtype=jnp.int32)[None, :])   # (nw, 32)
-        lanes = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
+        W, nw = bits.shape[1:]
+        off = jnp.arange(nw, dtype=jnp.int32) * 32       # word start - base
+        ones = jnp.uint32(0xFFFFFFFF)
 
         def step(_, xs):
             b, base_r, rowm, sb, se = xs
             counts = jnp.sum(_swar_pop_j(b), axis=1)         # (W,)
             active = rowm & (counts > 0)
-            page = base_r[:, None, None] + col[None]         # (W, nw, 32)
-            flat = page.reshape(-1)
-            cov = (jnp.searchsorted(sb, flat, side="right")
-                   - jnp.searchsorted(se, flat, side="right"))
-            multi = (cov >= 2).reshape(page.shape)
-            mbits = jnp.sum(jnp.where(multi, lanes, jnp.uint32(0)),
-                            axis=-1, dtype=jnp.uint32)       # (W, nw)
-            shared = jnp.where(active[:, None], b & mbits, jnp.uint32(0))
+            flips = _flip_points_j(sb, se)                   # (2W,)
+            end = base_r + 32 * nw                           # past the row
+            lo = jnp.sum(flips[None, :] <= base_r[:, None], axis=1)
+            hi = jnp.sum(flips[None, :] < end[:, None], axis=1)
+            start = jnp.where(lo % 2 == 1, ones, jnp.uint32(0))
+            mask = jnp.broadcast_to(start[:, None], b.shape)
+
+            def flip(i, mask):
+                k = lo + i                                    # (W,)
+                f = jnp.where(k < hi, flips[jnp.minimum(k, 2 * W - 1)],
+                              end)
+                d = (f - base_r)[:, None] - off[None, :]      # (W, nw)
+                part = ones << jnp.clip(d, 0, 31).astype(jnp.uint32)
+                return mask ^ jnp.where(d < 32, part, jnp.uint32(0))
+
+            passes = jnp.max(jnp.where(active, hi - lo, 0))
+            mask = jax.lax.fori_loop(0, passes, flip, mask)
+            shared = jnp.where(active[:, None], b & mask, jnp.uint32(0))
             return None, (counts, shared)
 
         _, (counts, shared) = jax.lax.scan(
@@ -635,10 +669,12 @@ def phase_step(bits: np.ndarray, base: np.ndarray, rowmask: np.ndarray,
     """The fused barrier-flush chain ('pallas-jit' only): R stacked
     regions' packed dirty planes in, per-row dirty counts + packed
     shared-dirty candidate masks out, as ONE jitted device dispatch
-    (``lax.scan`` over the region axis).  Inputs per
-    ``_phase_step_jit``; numpy fallback exists only for the oracle
-    tests — the runtime routes non-jit backends through the unfused
-    path."""
+    (``lax.scan`` over the region axis).  The device builds each
+    region's multi-coverage mask a 32-page word at a time from the flip
+    points of the window bounds (``_phase_step_jit``); the numpy
+    fallback ``_phase_step_np`` stabs every page with ``searchsorted``
+    and exists only as the oracle of the tests — the runtime routes
+    non-jit backends through the unfused path."""
     if resolve_backend("pallas-jit") == "pallas-jit":
         counts, shared = _dispatch(
             "phase_step", _phase_step_jit,

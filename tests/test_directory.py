@@ -268,36 +268,104 @@ def test_jit_kernels_match_numpy_oracles():
     assert st["jit_dispatches"] == 5, st
 
 
-def test_phase_step_jit_matches_numpy_oracle():
-    """The fused barrier-flush chain vs its numpy oracle on randomized
-    multi-region stacks: per-row dirty counts AND the packed
-    shared-dirty candidate masks (dirty & >=2-coverage & active row),
-    including inactive rows (base=-1), masked rows, and INT32_MAX
-    geometry padding."""
+I32MAX = np.iinfo(np.int32).max
+
+
+def _flush_stack(regions, nw, rng):
+    """An (R, W, nw) fused-flush batch from per-region row windows: each
+    region is a list of (base, length) rows, base -1 for a dead row.  Live
+    rows get random dirty bits over their whole packed row, dead rows none;
+    the sorted live bounds are padded with INT32_MAX."""
+    R, W = len(regions), len(regions[0])
+    bits = rng.integers(0, 1 << 32, (R, W, nw), dtype=np.uint64)
+    bits = (bits & rng.integers(0, 1 << 32, (R, W, nw),
+                                dtype=np.uint64)).astype(np.uint32)
+    base = np.full((R, W), -1, np.int32)
+    sbs = np.full((R, W), I32MAX, np.int32)
+    ses = np.full((R, W), I32MAX, np.int32)
+    for r, rows in enumerate(regions):
+        live = [(w, b, n) for w, (b, n) in enumerate(rows) if b >= 0]
+        for w, b, _ in live:
+            base[r, w] = b
+        bits[r, base[r] < 0] = 0
+        sbs[r, :len(live)] = np.sort([b for _, b, _ in live])
+        ses[r, :len(live)] = np.sort([b + n for _, b, n in live])
+    return bits, base, sbs, ses
+
+
+def _random_regions(rng):
+    """Three regions of seven rows, some dead, windows of 1-200 pages over
+    a 5000-page span (the multi-region stacks the runtime batches)."""
+    regions = []
+    for _ in range(3):
+        rows = [(-1, 0)] * 7
+        for w in rng.choice(7, int(rng.integers(2, 8)), replace=False):
+            rows[w] = (int(rng.integers(0, 5000)), int(rng.integers(1, 201)))
+        regions.append(rows)
+    return regions, 7
+
+
+def _jacobi_halo(nw):
+    """Jacobi's flush geometry at W=256, each worker's window its
+    16384-page block: nw 1024 is the stencil read plus a 64-page halo on
+    each side, nw 513 the copy phase's windows that reach one page into
+    the next block; a seeded permutation places the blocks."""
+    W, B = 256, 16384
+    halo = 64 if nw == 1024 else 0
+    rows = []
+    for blk in np.random.default_rng(5).permutation(W):
+        lo = max(int(blk) * B - halo, 0)
+        hi = min((int(blk) + 1) * B + (halo or 1), W * B)
+        rows.append((lo, hi - lo))
+    return [rows], nw
+
+
+FLUSH_GEOMETRIES = {
+    # windows shorter than one 32-page word, several flips in one word
+    "short_windows": lambda rng: ([[(3, 2), (4, 5), (6, 1), (9, 20),
+                                    (10, 3), (40, 7), (44, 1), (45, 30)]], 3),
+    # bases off the 32-page grid, overlaps straddling word edges
+    "unaligned_bases": lambda rng: ([[(13, 70), (31, 33), (63, 2), (65, 90),
+                                      (97, 31), (150, 17)]], 5),
+    # nested and identical windows: coverage 3 and 4 stays one interval
+    "nested_identical": lambda rng: ([[(0, 300), (50, 100), (50, 100),
+                                       (60, 20), (200, 1), (200, 1)]], 10),
+    # one window ends where the next starts: no gap, no overlap
+    "touching": lambda rng: ([[(0, 64), (64, 64), (128, 5), (133, 40),
+                               (100, 33), (140, 1)]], 6),
+    # dead rows (base -1) among live ones, INT32_MAX pads in the bounds
+    "dead_rows_and_pads": lambda rng: ([[(0, 50), (-1, 0), (30, 60),
+                                         (-1, 0), (45, 3)],
+                                        [(-1, 0)] * 5,
+                                        [(-1, 0), (7, 9), (-1, 0), (-1, 0),
+                                         (-1, 0)]], 4),
+    # bounds just under the int32 guard of RegCScaleRuntime._jit_flush_chain
+    # (base + 32 * nw must stay below INT32_MAX)
+    "int32_guard": lambda rng: ([[(I32MAX - 1 - 32 * 8 - d, n)
+                                  for d, n in ((0, 256), (40, 100), (100, 90),
+                                               (200, 77), (3, 1))]], 8),
+    "random_stacks": _random_regions,
+    "jacobi_w256_nw1024": lambda rng: _jacobi_halo(1024),
+    "jacobi_w256_nw513": lambda rng: _jacobi_halo(513),
+}
+
+
+@pytest.mark.parametrize("geometry", list(FLUSH_GEOMETRIES))
+def test_phase_step_jit_matches_numpy_oracle(geometry):
+    """The fused barrier-flush chain (flip points, then word masks) vs its
+    per-page searchsorted numpy oracle: per-row dirty counts AND the packed
+    shared-dirty candidate masks (dirty & >=2-coverage & active row), bit
+    for bit, in one device dispatch, on adversarial window geometries and
+    on the two benchmark cells' Jacobi shapes."""
     pytest.importorskip("jax")
     from repro.kernels import protocol_sweep as ps
     rng = np.random.default_rng(41)
-    i32max = np.iinfo(np.int32).max
-    for trial in range(4):
-        R, W, C = 3, 7, int(rng.integers(40, 200))
-        nw = -(-C // 32)
-        bits = np.zeros((R, W, nw), np.uint32)
-        base = np.full((R, W), -1, np.int32)
-        sbs = np.full((R, W), i32max, np.int32)
-        ses = np.full((R, W), i32max, np.int32)
-        for r in range(R):
-            nlive = int(rng.integers(2, W + 1))
-            rows = rng.choice(W, nlive, replace=False)
-            b = np.sort(rng.integers(0, 5000, nlive)).astype(np.int32)
-            ln = rng.integers(1, C + 1, nlive).astype(np.int32)
-            base[r, rows] = b
-            sbs[r, :nlive] = np.sort(b)
-            ses[r, :nlive] = np.sort(b + ln)
-            for i, w in enumerate(rows):
-                plane = np.zeros(C, bool)
-                plane[:ln[i]] = rng.random(int(ln[i])) < 0.4
-                bits[r, w] = ps.pack_mask_rows(plane[None])[0]
-        rowmask = rng.random((R, W)) < 0.8
+    candidates = 0
+    for trial in range(4 if geometry == "random_stacks" else 1):
+        regions, nw = FLUSH_GEOMETRIES[geometry](rng)
+        bits, base, sbs, ses = _flush_stack(regions, nw, rng)
+        rowmask = rng.random(base.shape) < 0.8
+        rowmask[:, ::2] = True           # even rows flushed, odd ones drawn
         st = {}
         counts, shared = ps.phase_step(bits, base, rowmask, sbs, ses,
                                        stats=st)
@@ -306,6 +374,8 @@ def test_phase_step_jit_matches_numpy_oracle():
         np.testing.assert_array_equal(counts, counts_np, err_msg=str(trial))
         np.testing.assert_array_equal(shared, shared_np, err_msg=str(trial))
         assert st["jit_dispatches"] == 1, st
+        candidates += int(np.count_nonzero(shared_np))
+    assert candidates > 0            # every geometry has shared dirty pages
 
 
 def test_force_numpy_env_override_wins():

@@ -56,14 +56,18 @@ def _compile(fn, *args):
     return compiled, compiled.as_text()
 
 
-def test_phase_step_compiles(one_chip):
-    R, nw = 3, 1024                                # 32k-page window
+@pytest.mark.parametrize("R,nw", [(3, 1024), (1, 1024), (1, 513)])
+def test_phase_step_compiles(one_chip, R, nw):
+    """The fused flush at the benchmark cells' shapes (one region of 1024
+    or 513 words, a 32k- or 16k-page window) and a three-region stack.
+    Its scratch stays below one int32 entry per page of one region, the
+    (W, nw, 32) grid a per-page coverage stab would build."""
     i32 = lambda: _spec(one_chip, (R, W), jnp.int32)  # noqa: E731
     compiled, _ = _compile(
         ps._phase_step_jit, _spec(one_chip, (R, W, nw), jnp.uint32), i32(),
         _spec(one_chip, (R, W), jnp.bool_), i32(), i32())
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 16 << 30
+    assert mem.temp_size_in_bytes < W * 1024 * 32 * 4     # 33,554,432
 
 
 def test_take_and_cut_compiles(one_chip):
